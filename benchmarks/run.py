@@ -80,6 +80,8 @@ def _append_history(name: str, out_dir: str, smoke: bool) -> None:
 
 
 def main() -> None:
+    from repro.launch.cache import use_compile_cache
+    use_compile_cache()
     argv = sys.argv
     smoke = "--smoke" in argv[1:]
     selectors = [a for a in argv[1:] if a != "--smoke"]

@@ -355,12 +355,6 @@ class ComposedPolicy(ExpansionPolicy):
 _KERNEL_CACHE: dict[tuple, Callable] = {}
 
 
-def _donate(n: int) -> tuple:
-    # Buffer donation is a no-op (with a warning) on CPU; only request it
-    # where the backend honors it.
-    return tuple(range(n)) if jax.default_backend() != "cpu" else ()
-
-
 def variance_stats(objective: Objective, w, data, k: int):
     """(‖Var_i ∇ℓ_i‖₁ / k, ‖ḡ‖²) over the first ``k`` rows of (X, y) —
     per-example gradients via vmap; the DSM / GradientVariance test."""
@@ -436,7 +430,7 @@ def _scan_kernel(optimizer, objective, *, eval_full: bool,
         return res
 
     jitted = jax.jit(kernel, static_argnames=("num_steps", "probe_k"),
-                     donate_argnums=_donate(2))
+                     donate_argnums=(0, 1))
     _KERNEL_CACHE[key] = jitted
     return jitted
 
@@ -498,7 +492,7 @@ def _two_track_kernel(optimizer, objective, *, condition_eval: bool,
         return out
 
     jitted = jax.jit(kernel, static_argnames=("max_iters",),
-                     donate_argnums=_donate(3))
+                     donate_argnums=(0, 1, 2))
     _KERNEL_CACHE[key] = jitted
     return jitted
 

@@ -230,9 +230,8 @@ def _append_kernel(buf_shape, rows_shape, dtype, sharding, *,
             start = (offset,) + (jnp.int32(0),) * (buf.ndim - 1)
             return jax.lax.dynamic_update_slice(buf, rows, start)
 
-    donate = (0,) if jax.default_backend() != "cpu" else ()
     kw = {"out_shardings": sharding} if sharding is not None else {}
-    _APPEND_CACHE[key] = jax.jit(append, donate_argnums=donate, **kw)
+    _APPEND_CACHE[key] = jax.jit(append, donate_argnums=(0,), **kw)
     return _APPEND_CACHE[key]
 
 
@@ -246,11 +245,11 @@ class DeviceWindow:
     ``growth`` mirrors the stage schedule and is validated like
     ``BETSchedule.growth`` — a factor <= 1 would never fill the window.
 
-    View lifetime: on backends that honor buffer donation (non-CPU), an
-    ``append`` consumes the previous buffer in place, invalidating views
-    handed out earlier.  Take ``masked()``/``slice()`` views *after* the
-    stage's residency is settled and drop them before the next expansion —
-    the engine's acquire-then-view stage setup follows this order."""
+    View lifetime: an ``append`` donates the previous buffer and lands the
+    rows in place, invalidating views handed out earlier.  Take
+    ``masked()``/``slice()`` views *after* the stage's residency is
+    settled and drop them before the next expansion — the engine's
+    acquire-then-view stage setup follows this order."""
     capacity: int
     item_shape: tuple
     dtype: Any

@@ -17,10 +17,11 @@ with two implementations:
     and each logical host gets its own CPU device, the hosts mesh is real,
     and the stacked window (data/device_window.StackedDeviceWindow) is
     genuinely sharded one lane per host — the whole runtime is then testable
-    on CPU CI.  With fewer devices than hosts (the plain single-device test
-    environment) the logical hosts share devices and the mesh degrades to
-    ``None``; all ownership/collective *math* is unchanged, only placement
-    is.
+    on CPU CI.  With fewer CPU devices than hosts (the plain single-device
+    test environment) the logical hosts share devices and the mesh degrades
+    to ``None``; all ownership/collective *math* is unchanged, only
+    placement is.  On an accelerator that sharing would hide a wrong
+    placement, so there fewer devices than hosts is an error.
 
 Everything here must be import-safe before device state matters: topologies
 query ``jax.devices()`` lazily, at construction."""
@@ -83,8 +84,9 @@ class SimulatedTopology(HostTopology):
 
     With ``len(devices) >= num_hosts`` the pool is split into contiguous
     per-host groups (forced-host-platform CI, or one logical host per
-    accelerator); otherwise hosts share devices cyclically and no hosts mesh
-    exists — placement degrades, semantics do not."""
+    accelerator).  With fewer CPU devices, hosts share devices cyclically
+    and no hosts mesh exists — placement degrades, semantics do not; with
+    fewer accelerator devices the topology refuses to build."""
 
     def __init__(self, num_hosts: int, *, devices=None):
         if num_hosts < 1:
@@ -92,6 +94,12 @@ class SimulatedTopology(HostTopology):
         self._num_hosts = int(num_hosts)
         self._devices = tuple(devices) if devices is not None \
             else tuple(jax.devices())
+        platform = self._devices[0].platform
+        if len(self._devices) < self._num_hosts and platform != "cpu":
+            raise ValueError(
+                f"{self._num_hosts} simulated hosts need a {platform} "
+                f"device each, but only {len(self._devices)} are visible; "
+                f"sharing devices between hosts is a CPU-test fallback")
 
     @property
     def num_hosts(self) -> int:

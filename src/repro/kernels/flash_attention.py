@@ -76,7 +76,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
                                              "block_k", "interpret"))
 def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
                     block_q: int = 128, block_k: int = 128,
-                    interpret: bool = True):
+                    interpret: bool = False):
     """q,k,v: (B, H, S, hd) -> (B, H, S, hd).  S must divide by the blocks
     (ops.py pads)."""
     B, H, S, hd = q.shape

@@ -48,7 +48,7 @@ def _kernel(x_ref, y_ref, w_ref, g_ref, l_ref, *, loss: str):
 
 @functools.partial(jax.jit, static_argnames=("loss", "block_m", "interpret"))
 def linear_value_grad(X, y, w, *, loss: str = "squared_hinge",
-                      block_m: int = 128, interpret: bool = True):
+                      block_m: int = 128, interpret: bool = False):
     """Returns (Σ loss_i, ∇_w Σ loss_i).  X: (n, d) — n must divide by
     block_m (ops.py pads); w: (d,)."""
     n, d = X.shape

@@ -1,8 +1,10 @@
 """jit'd public wrappers around the Pallas kernels: shape normalization
 (padding to block multiples, GQA head expansion) + dispatch.
 
-``interpret=True`` everywhere in this container (CPU validation); on real
-TPU hardware set ``repro.kernels.ops.INTERPRET = False``.
+Interpret mode is chosen at dispatch time from the platform: the Pallas
+interpreter runs the kernels only where ``jax.default_backend()`` is the
+CPU (the tests, which ``tests/conftest.py`` pins there); on a TPU every
+kernel is compiled by Mosaic.  Nothing reaches the chip interpreted.
 
 The scan kernels (``ssm_scan``/``rglru_scan``) and ``flash_attention``
 are differentiable: the Pallas kernel is the forward pass and the backward
@@ -28,13 +30,39 @@ from . import ref as _ref
 from . import rglru_scan as _rg
 from . import ssm_scan as _ss
 
-INTERPRET = True
-
 CALLS: collections.Counter = collections.Counter()
 
 
 def reset_calls() -> None:
     CALLS.clear()
+
+
+def _interpret() -> bool:
+    return jax.default_backend() == "cpu"
+
+
+def _lane_block(width: int, block: int) -> int:
+    """The widest multiple of 128 lanes <= ``block`` that divides
+    ``width``, or the whole width when it is not a multiple of 128."""
+    if width % 128:
+        return width
+    bd = max(128, block - block % 128)
+    while width % bd:
+        bd -= 128
+    return bd
+
+
+def _time_block(S: int) -> tuple[int, int]:
+    """(time block, S padded to a multiple of it) for the scan kernels: at
+    most 512 rows, always a multiple of 128."""
+    ts = min(512, -(-S // 128) * 128)
+    return ts, -(-S // ts) * ts
+
+
+def _pad_time(x, S_pad: int):
+    S = x.shape[1]
+    return x if S == S_pad else jnp.pad(
+        x, ((0, 0), (0, S_pad - S)) + ((0, 0),) * (x.ndim - 2))
 
 
 def linear_forward(X, w):
@@ -53,7 +81,7 @@ def linear_value_grad(X, y, w, *, loss: str = "squared_hinge",
         # loss contribution of pad rows is constant wrt w but nonzero; fix by
         # subtracting it below.
     L, g = _lg.linear_value_grad(X, y, w, loss=loss, block_m=block_m,
-                                 interpret=INTERPRET)
+                                 interpret=_interpret())
     if pad:
         if loss == "squared_hinge":
             L = L - pad * 1.0          # each zero-row contributes ℓ(0) = 1
@@ -66,7 +94,7 @@ def linear_value_grad(X, y, w, *, loss: str = "squared_hinge",
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6))
 def _fa_diff(qT, kT, vT, causal, window, bq, bk):
     return _fa.flash_attention(qT, kT, vT, causal=causal, window=window,
-                               block_q=bq, block_k=bk, interpret=INTERPRET)
+                               block_q=bq, block_k=bk, interpret=_interpret())
 
 
 def _fa_fwd(qT, kT, vT, causal, window, bq, bk):
@@ -115,8 +143,14 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
 # --------------------------------------------------------------- ssm scan
 @functools.partial(jax.custom_vjp, nondiff_argnums=(6,))
 def _ssm_diff(u, delta, B_ssm, C_ssm, A_log, D, bd):
-    return _ss.ssm_scan(u, delta, B_ssm, C_ssm, A_log, D, block_d=bd,
-                        interpret=INTERPRET)
+    # pad S to the kernel's time block: zero steps after the end never
+    # reach a real output (the scan is causal), and are sliced back off
+    S = u.shape[1]
+    ts, S_pad = _time_block(S)
+    y = _ss.ssm_scan(*(_pad_time(x, S_pad) for x in (u, delta, B_ssm, C_ssm)),
+                     A_log, D, block_d=bd, block_t=ts,
+                     interpret=_interpret())
+    return y[:, :S]
 
 
 def _ssm_fwd(u, delta, B_ssm, C_ssm, A_log, D, bd):
@@ -134,17 +168,18 @@ _ssm_diff.defvjp(_ssm_fwd, _ssm_bwd)
 
 def ssm_scan(u, delta, B_ssm, C_ssm, A_log, D, *, block_d: int = 256):
     CALLS["ssm_scan"] += 1
-    di = u.shape[-1]
-    bd = min(block_d, di)
-    while di % bd:
-        bd -= 1
-    return _ssm_diff(u, delta, B_ssm, C_ssm, A_log, D, bd)
+    return _ssm_diff(u, delta, B_ssm, C_ssm, A_log, D,
+                     _lane_block(u.shape[-1], block_d))
 
 
 # ------------------------------------------------------------- rglru scan
 @functools.partial(jax.custom_vjp, nondiff_argnums=(2,))
 def _rglru_diff(a, b, bw):
-    return _rg.rglru_scan(a, b, block_w=bw, interpret=INTERPRET)
+    S = a.shape[1]
+    ts, S_pad = _time_block(S)
+    y = _rg.rglru_scan(_pad_time(a, S_pad), _pad_time(b, S_pad), block_w=bw,
+                       block_t=ts, interpret=_interpret())
+    return y[:, :S]
 
 
 def _rglru_fwd(a, b, bw):
@@ -161,4 +196,4 @@ _rglru_diff.defvjp(_rglru_fwd, _rglru_bwd)
 
 def rglru_scan(a, b, *, block_w: int = 256):
     CALLS["rglru_scan"] += 1
-    return _rglru_diff(a, b, min(block_w, a.shape[-1]))
+    return _rglru_diff(a, b, _lane_block(a.shape[-1], block_w))

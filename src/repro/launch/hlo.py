@@ -255,9 +255,5 @@ def analyze(hlo_text: str) -> dict:
 
 
 def raw_cost_analysis(compiled) -> dict:
-    """``compiled.cost_analysis()`` across jax versions: newer releases
-    return a list with one dict per partition; older ones a bare dict."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return dict(cost)
+    """``compiled.cost_analysis()``: one dict of XLA's cost counters."""
+    return dict(compiled.cost_analysis())

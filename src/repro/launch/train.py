@@ -11,13 +11,14 @@ Schedules map to policies: ``batch`` → NeverExpand, ``bet`` → FixedSteps
 (Alg. 1/3), ``two_track`` → TwoTrack (Alg. 2).  Stages run device-side in
 lax.scan / lax.while_loop chunks with a single host transfer per stage.
 
-On CPU it runs reduced configs end-to-end (examples/, tests); on real
-hardware the identical code paths run on the production mesh with the
-``fsdp_tp`` sharding policy.
+``--reduced`` (the default) trains the <=3-layer smoke variant that runs
+on CPU; ``--no-reduced`` trains the architecture at its published config,
+which needs an accelerator (qwen3-0.6b fits one TPU v5e chip).
 
 Usage:
-    PYTHONPATH=src python -m repro.launch.train --arch qwen3-0.6b --reduced \
-        --schedule two_track --stages 4 --inner-steps 8
+    PYTHONPATH=src python -m repro.launch.train --arch qwen3-0.6b \
+        --schedule two_track --inner-steps 8
+    PYTHONPATH=src python -m repro.launch.train --no-reduced --seq-len 1024
     PYTHONPATH=src python -m repro.launch.train --dry-run   # print the spec
 """
 from __future__ import annotations
@@ -32,6 +33,7 @@ from ..api import (CheckpointSpec, DataSpec, ElasticSpec, ModelSpec,
                    TopologySpec, build)
 from ..api.lm import LMStepOptimizer, TokenWindows, make_lm_objective  # noqa: F401 (compat re-export)
 from ..core.trace import Trace
+from .cache import use_compile_cache
 
 
 @dataclasses.dataclass
@@ -185,7 +187,10 @@ def main() -> None:
                     help="print the workload matrix (name + one-line "
                          "scenario description) and exit")
     ap.add_argument("--arch", type=str, default="qwen3-0.6b")
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action=argparse.BooleanOptionalAction,
+                    default=True,
+                    help="train the <=3-layer smoke variant (default); "
+                         "--no-reduced trains the published config")
     ap.add_argument("--schedule", type=str, default="bet",
                     choices=["batch", "bet", "two_track"])
     ap.add_argument("--inner-steps", type=int, default=8)
@@ -217,6 +222,7 @@ def main() -> None:
                     help="print the composed RunSpec (JSON) and the stage "
                          "plan, then exit without running")
     args = ap.parse_args()
+    use_compile_cache()
 
     if args.list_workloads:
         _list_workloads()

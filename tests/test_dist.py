@@ -331,6 +331,15 @@ def test_simulated_topology_degrades_without_devices():
         SimulatedTopology(0)
 
 
+def test_simulated_topology_refuses_shared_accelerator():
+    """Off the CPU, hosts sharing a device would hide a wrong placement."""
+    class Chip:
+        platform = "tpu"
+    with pytest.raises(ValueError, match="tpu device each"):
+        SimulatedTopology(4, devices=[Chip()])
+    assert SimulatedTopology(2, devices=[Chip(), Chip()]).num_hosts == 2
+
+
 # ------------------------------------------- forced-host-platform subprocess
 def test_simulated_hosts_on_forced_device_mesh():
     """The real thing, in miniature: 4 forced CPU devices, a ('hosts',)

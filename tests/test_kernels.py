@@ -1,4 +1,5 @@
-"""Pallas kernels vs pure-jnp oracles: shape/dtype sweeps, interpret=True."""
+"""Pallas kernels vs pure-jnp oracles: shape/dtype sweeps, run by the
+Pallas interpreter (ops.py picks it on the CPU)."""
 import jax
 import jax.numpy as jnp
 import pytest
@@ -75,8 +76,11 @@ def test_flash_attention_sliding_window(window):
 
 
 # ----------------------------------------------------------------- ssm scan
+# S=37 and S=600 are no multiple of the kernel's 8/16-row time tiles; 600
+# spans two 512-step time blocks, so the state carries across blocks
 @pytest.mark.parametrize("B,S,di,N", [(1, 32, 64, 4), (2, 64, 128, 16),
-                                      (1, 100, 96, 8)])
+                                      (1, 100, 96, 8), (2, 37, 256, 16),
+                                      (1, 600, 128, 16)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_ssm_scan_sweep(B, S, di, N, dtype):
     ks = jax.random.split(KEY, 4)
@@ -109,7 +113,8 @@ def test_ssm_scan_state_decay():
 
 
 # -------------------------------------------------------------- rglru scan
-@pytest.mark.parametrize("B,S,W", [(1, 32, 64), (2, 100, 96), (1, 64, 256)])
+@pytest.mark.parametrize("B,S,W", [(1, 32, 64), (2, 100, 96), (1, 64, 256),
+                                   (1, 37, 256), (2, 600, 128)])
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
 def test_rglru_scan_sweep(B, S, W, dtype):
     ks = jax.random.split(KEY, 2)

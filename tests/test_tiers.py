@@ -173,16 +173,18 @@ def test_bounded_ring_spills_then_repromotes_bit_exact():
     shard_bytes = SHARD * row_bytes(X, y)
     with tiered(X, y, hbm_rows=64, host_bytes=6 * shard_bytes) as tc:
         def sweep(n_t, k):
-            views = [tc.begin_stage(n_t)]
-            views += [tc.advance_window()
-                      for _ in tc.segment_steps(n_t, k)[1:]]
-            return views
+            # a view lives until the next advance, which donates its buffer
+            # to land the next segment in place: read each one before that
+            yield tc.begin_stage(n_t)
+            for _ in tc.segment_steps(n_t, k)[1:]:
+                yield tc.advance_window()
 
         tc.begin_stage(64, 128)
         tc.begin_stage(128, 256)                 # enter rotation
         for _ in tc.segment_steps(128, 2)[1:]:
             tc.advance_window()
-        sweep(256, 4)
+        for _ in sweep(256, 4):
+            pass
         assert tc.tier_meter.evictions > 0       # the budget actually bites
         assert tc.ring.resident_bytes <= 6 * shard_bytes + \
             len(tc.ring._protected) * shard_bytes
