@@ -52,6 +52,7 @@ from repro.api import (DataSpec, ModelSpec, OptimizerSpec,     # noqa: E402
                        build, convex_problem)
 from repro.kernels import ops, ref                             # noqa: E402
 from repro.launch.cache import use_compile_cache               # noqa: E402
+from repro.obs import profile                                  # noqa: E402
 
 # kernel forward vs kernels/ref.ssm_scan, as a fraction of max |ref|.  Both
 # run the same f32 recurrence in the same order; they differ only in the
@@ -89,24 +90,6 @@ class CompileClock:
             self.seconds += duration
 
 
-class StageCapture:
-    """Engine profiler hook: keeps the first stage kernel and the shapes
-    it was called with, so its compiled HLO can be read after the run."""
-
-    def __init__(self):
-        self.kernel = None
-
-    def observe(self, info, kernel, args, kwargs) -> None:
-        if self.kernel is None:
-            self.kernel, self.kwargs = kernel, kwargs
-            self.args = jax.tree_util.tree_map(
-                lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
-                                               sharding=x.sharding), args)
-
-    def hlo(self) -> str:
-        return self.kernel.lower(*self.args, **self.kwargs).compile().as_text()
-
-
 def _lm_spec(model: ModelSpec, *, batch: int, seq_len: int, steps: int,
              stages: int, eval_rows: int) -> RunSpec:
     """The CLI's LM spec (launch/train.py) on the streaming plane, whose
@@ -128,14 +111,14 @@ def _lm_spec(model: ModelSpec, *, batch: int, seq_len: int, steps: int,
                               carry_state=True))
 
 
-def _train_lm(spec: RunSpec, capture: StageCapture | None = None) -> dict:
+def _train_lm(spec: RunSpec, profiler=None) -> dict:
     """Run the LM session; stage 0 includes the compile, later stages are
     steady (timed between stage boundaries, after block_until_ready)."""
     t0 = time.perf_counter()
     session = build(spec)
     build_s = time.perf_counter() - t0
-    if capture is not None:
-        session.engine.profiler = capture
+    if profiler is not None:
+        session.engine.profiler = profiler
     stamps = []
 
     def stamp(end):
@@ -241,13 +224,15 @@ def phase_c(*, model: ModelSpec = ModelSpec(
             parity_shape=(1, 2048, 8192, 16)) -> dict:
     """Train through the mamba family; returns what the checks read."""
     ops.reset_calls()
-    capture = StageCapture()
+    profile.STAGE_KERNELS.clear()
     res = _train_lm(_lm_spec(model, batch=batch, seq_len=seq_len,
                              steps=steps, stages=stages,
-                             eval_rows=eval_rows), capture)
+                             eval_rows=eval_rows), profile.StageProfiler())
     _check_lm(f"C {model.arch}", res, falls=False)
     res["calls"] = ops.CALLS["ssm_scan"]
-    res["hlo"] = capture.hlo()
+    # the first stage kernel's executable, as compiled for the run
+    lowered, _ = next(iter(profile.STAGE_KERNELS.values()))
+    res["hlo"] = lowered.compile().as_text()
     res["parity"] = {}
     for dtype in (jnp.float32, jnp.bfloat16):
         name = jnp.dtype(dtype).name

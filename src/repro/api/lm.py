@@ -10,6 +10,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Any, Callable
 
+import jax
 import jax.numpy as jnp
 
 from ..data.device_window import probe_rows, rotation_rows
@@ -41,8 +42,9 @@ class LMStepOptimizer(BatchOptimizer):
         # host rotates through its *own* lane and the global batch is the
         # concatenation of the per-host sub-batches (dist data parallelism).
         # One lane-aware gather serves all three (data/device_window.py).
-        rows = rotation_rows(data, self.batch_size, state["t"])
-        batch = {"tokens": rows[:, :-1], "labels": rows[:, 1:]}
+        with jax.named_scope("bet.batch"):
+            rows = rotation_rows(data, self.batch_size, state["t"])
+            batch = {"tokens": rows[:, :-1], "labels": rows[:, 1:]}
         params, opt, metrics = self.train_step(params, state["opt"], batch)
         return params, {"opt": opt, "t": state["t"] + 1}, {"f": metrics["loss"]}
 

@@ -617,9 +617,8 @@ class Session:
             from ..obs.health import HealthMonitor
             self.health = HealthMonitor(slo=obs.slo)
             self.health.attach(rec)
-        if obs.profile:
-            from ..obs.profile import StageProfiler
-            self.engine.profiler = StageProfiler(rec)
+        from ..obs.profile import StageProfiler
+        self.engine.profiler = StageProfiler()
 
     def run_report(self):
         """The :class:`~repro.obs.report.RunReport` over this session's

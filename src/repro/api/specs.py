@@ -277,9 +277,10 @@ class ObsSpec(_Spec):
     stack emits nothing and trajectories are bit-identical to an
     uninstrumented run.  ``dir`` lands ``events.jsonl`` (+ ``trace.json``
     when ``chrome_trace``, + ``report.json``/``report.txt`` when
-    ``report``) after the run; ``profile`` wires the per-stage HLO cost
-    estimator, and ``jax_profiler_dir`` additionally captures a
-    ``jax.profiler`` trace.
+    ``report``) after the run, and ``jax_profiler_dir`` additionally
+    captures a ``jax.profiler`` trace, in which every recorder span is a
+    ``TraceAnnotation`` and each stage kernel's ops can be named by scope
+    (``repro.obs.profile.stage_op_names``).
 
     ``fleet`` (multi-host only) gives every simulated host its own event
     lane — ``dir`` then additionally lands one ``events_host<h>.jsonl``
@@ -293,7 +294,6 @@ class ObsSpec(_Spec):
     dir: str | None = None          # event log / trace / report directory
     chrome_trace: bool = False      # also export trace.json (Perfetto)
     report: bool = True             # write RunReport when dir is set
-    profile: bool = False           # per-stage HLO FLOP/byte estimates
     jax_profiler_dir: str | None = None
     fleet: bool = False             # per-host event lanes + merged trace
     health: bool = False            # live health detectors + HealthReport

@@ -37,7 +37,6 @@ parity tests and benchmarks/bench_engine.py.
 """
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import math
 from typing import Any, Callable
@@ -46,6 +45,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..obs.events import obs_span
 from ..optim.api import BatchOptimizer, Objective
 from .timemodel import SimulatedClock
 from .trace import Trace
@@ -416,7 +416,8 @@ def _scan_kernel(optimizer, objective, *, eval_full: bool,
         def collect(p, aux):
             out = {"f": aux["f"]}
             if eval_full:
-                out["f_full"] = objective(p, full_data)
+                with jax.named_scope("bet.probe"):
+                    out["f_full"] = objective(p, full_data)
             if collect_params:
                 out["w"] = p
             return out
@@ -461,7 +462,8 @@ def _two_track_kernel(optimizer, objective, *, condition_eval: bool,
                                           objective, win_prev)
             f_slow = objective(w_s, win_t) if condition_eval else aux["f"]
             f_fast = objective(w_f, win_t)
-            f_full = objective(w_s, full_data)
+            with jax.named_scope("bet.probe"):
+                f_full = objective(w_s, full_data)
             s = c["s"]
             hs = c["hist_slow"].at[s].set(f_slow)
             hf = c["hist_fast"].at[s].set(f_fast)
@@ -497,14 +499,6 @@ def _two_track_kernel(optimizer, objective, *, condition_eval: bool,
     return jitted
 
 
-def _obs_span(recorder, name: str, **fields):
-    """A recorder span when observability is wired, a no-op otherwise —
-    every engine hook is one ``None`` check when ``ObsSpec`` is off."""
-    if recorder is None:
-        return contextlib.nullcontext({})
-    return recorder.span(name, **fields)
-
-
 # ---------------------------------------------------------------- the engine
 @dataclasses.dataclass
 class BetEngine:
@@ -529,8 +523,9 @@ class BetEngine:
     stage_callback: Callable | None = None
     # observability (repro.obs): a wired EventRecorder makes the engine emit
     # structured stage spans/instants/counters; a StageProfiler additionally
-    # lowers each stage's kernel once for analytic FLOP/byte costs.  Both
-    # off by default — the stage trajectory is bit-identical either way.
+    # keeps each stage kernel's executable, so its compiled ops can be named
+    # by scope (repro.obs.profile).  Both off by default — the stage
+    # trajectory is bit-identical either way.
     recorder: Any | None = None
     profiler: Any | None = None
 
@@ -728,7 +723,7 @@ class BetEngine:
             obs.set_context(stage=info.stage)
             obs.instant("stage.begin", window=info.n_t, n_next=info.n_next,
                         final=info.is_final)
-        with _obs_span(obs, "stage.acquire", window=info.n_t):
+        with obs_span(obs, "stage.acquire", window=info.n_t):
             win = self._acquire_window(dataset, info.n_t, info.n_next)
         if self.wait_on_expand:
             clock.wait_for(info.n_t)
@@ -745,16 +740,16 @@ class BetEngine:
             for seg_j, (kj, seg_n) in enumerate(plan):
                 if seg_j:
                     # rotation: land the next pre-staged sweep segment
-                    with _obs_span(obs, "stage.acquire", window=info.n_t,
-                                   segment=seg_n):
+                    with obs_span(obs, "stage.acquire", window=info.n_t,
+                                  segment=seg_n):
                         win = dataset.advance_window()
                 pk = probe_k if seg_n is None else min(probe_k, seg_n)
                 if self.profiler is not None and rec.steps == 0:
                     self.profiler.observe(info, kernel,
                                           (w, state, win, full_data),
                                           {"num_steps": kj, "probe_k": pk})
-                with _obs_span(obs, "stage.compute", steps=kj,
-                               window=info.n_t):
+                with obs_span(obs, "stage.compute", steps=kj,
+                              window=info.n_t):
                     out = kernel(w, state, win, full_data, num_steps=kj,
                                  probe_k=pk)
                     w, state = out["params"], out["state"]
@@ -782,7 +777,7 @@ class BetEngine:
             if rec.steps > self.max_engine_steps:
                 raise RuntimeError(
                     f"policy {policy.name} never expanded after {rec.steps} steps")
-        with _obs_span(obs, "stage.flush", window=info.n_t):
+        with obs_span(obs, "stage.flush", window=info.n_t):
             self._flush_stage(ctx, policy, info, rec, extra_base=extra_base,
                               eval_charge=probe_k, chunk_costs=chunk_costs)
         policy.stage_end(info, rec)
@@ -907,7 +902,7 @@ class BetEngine:
                 obs.set_context(stage=stage)
                 obs.instant("stage.begin", window=n_t, n_next=n_next,
                             final=info.is_final)
-            with _obs_span(obs, "stage.acquire", window=n_t):
+            with obs_span(obs, "stage.acquire", window=n_t):
                 win_t = self._acquire_window(dataset, n_t, n_next)
                 win_prev = dataset.window(n_prev)  # resident prefix: no loads
             if self.wait_on_expand:
@@ -930,7 +925,7 @@ class BetEngine:
                         info, kernel,
                         (w, st_slow, st_fast, win_t, win_prev, full_data),
                         {"max_iters": int(policy.max_stage_iters)})
-                with _obs_span(obs, "stage.compute", window=n_t):
+                with obs_span(obs, "stage.compute", window=n_t):
                     out = kernel(w, st_slow, st_fast, win_t, win_prev,
                                  full_data,
                                  max_iters=int(policy.max_stage_iters))
@@ -974,7 +969,7 @@ class BetEngine:
                 st_slow = state
                 st_fast = optimizer.init(w)
             s = rec.steps
-            with _obs_span(obs, "stage.flush", window=n_t):
+            with obs_span(obs, "stage.flush", window=n_t):
                 self._collect_host_records(ctx, info)
                 # replay the per-step clock charges: slow update, fast
                 # update, condition evaluation (charged per the paper unless

@@ -25,6 +25,7 @@ from typing import Any, Sequence
 
 import numpy as np
 
+from ..obs.events import obs_span
 from .device_window import DeviceWindow
 from .prefetch import Prefetcher
 from .shards import (DataAccessMeter, InMemoryShardStore, ShardStore,
@@ -129,27 +130,31 @@ class StreamingDataset:
         self.prefetcher.schedule(range(self._next_shard, need))
         chunks = [[] for _ in self.stores]
         try:
-            while self._next_shard < need:
-                arrays = self.prefetcher.take(self._next_shard)
-                for acc, rows in zip(chunks, arrays):
-                    acc.append(rows)
-                self._next_shard += 1
+            with self._span("plane.wait", shards=need - self._next_shard):
+                while self._next_shard < need:
+                    arrays = self.prefetcher.take(self._next_shard)
+                    for acc, rows in zip(chunks, arrays):
+                        acc.append(rows)
+                    self._next_shard += 1
         finally:
             # land whatever was taken even when a later take raises
             # (ShardLoadError mid-expansion): _next_shard must never run
             # ahead of appended rows, or a retried call would append later
             # shards at the failed shards' window offsets
-            for win, acc in zip(self.windows, chunks):
-                if acc:
-                    win.append(acc[0] if len(acc) == 1
-                               else np.concatenate(acc))
+            with self._span("plane.append"):
+                for win, acc in zip(self.windows, chunks):
+                    if acc:
+                        win.append(acc[0] if len(acc) == 1
+                                   else np.concatenate(acc))
         return self.resident
 
     def prefetch(self, n: int) -> None:
         """Schedule background loads so the first ``n`` examples will be
         takeable without blocking (the next stage's shards)."""
         need = self.stores[0].shards_covering(n)
-        self.prefetcher.schedule(range(self._next_shard, need.stop))
+        with self._span("plane.prefetch",
+                        shards=max(0, need.stop - self._next_shard)):
+            self.prefetcher.schedule(range(self._next_shard, need.stop))
 
     def begin_stage(self, n_t: int, n_next: int | None = None):
         """Engine stage setup: make the stage window resident, overlap the
@@ -198,6 +203,12 @@ class StreamingDataset:
         return self.prefetcher.cancel(stale)
 
     # ------------------------------------------------------------------ misc
+    def _span(self, name: str, **fields):
+        """A span on the recorder the prefetcher carries (none when
+        observability is off), tagged as its events are."""
+        return obs_span(self.prefetcher.recorder, name,
+                        tags=self.prefetcher.recorder_tags or None, **fields)
+
     def _view(self, n_t: int):
         if self.masked:
             return self.windows[0].masked(n_t)

@@ -132,10 +132,16 @@ class Prefetcher:
             new_ids = [i for i in shard_ids
                        if i not in self._pending and i not in self._backlog]
             self._backlog.extend(new_ids)
+        # emit outside the lock, and before the loads start: a worker's
+        # prefetch.loaded must follow its shard's prefetch.scheduled even
+        # when this thread is descheduled between submit and emit
+        for i in new_ids:
+            self._obs("prefetch.scheduled", shard=int(i))
+        with self._lock:
+            if self._closed:
+                return
             self._pump_locked()
             inflight, backlog = len(self._pending), len(self._backlog)
-        for i in new_ids:        # emit outside the lock
-            self._obs("prefetch.scheduled", shard=int(i))
         if new_ids:
             self._obs_depth(inflight, backlog)
 

@@ -47,6 +47,7 @@ from typing import Sequence
 import jax
 import numpy as np
 
+from ...obs.events import obs_span
 from ..device_window import DeviceWindow
 from ..prefetch import Prefetcher
 from ..shards import DataAccessMeter, ShardStore, store_capacity
@@ -223,11 +224,12 @@ class TieredCorpus:
         if not st.append:
             for w in self.windows:
                 w.restore_cursor({"n_valid": 0})
-        for i, (w, rows) in enumerate(zip(self.windows, dev)):
-            w.append_staged(rows)
-            self.meter.record_upload(
-                nbytes=int(rows.nbytes),
-                examples=(st.hi - st.lo) if i == 0 else 0)
+        with obs_span(self._recorder, "plane.append", staged=True):
+            for i, (w, rows) in enumerate(zip(self.windows, dev)):
+                w.append_staged(rows)
+                self.meter.record_upload(
+                    nbytes=int(rows.nbytes),
+                    examples=(st.hi - st.lo) if i == 0 else 0)
         reup = max(0, min(st.hi, prev_hi) - max(st.lo, prev_lo))
         self.tier_meter.record_promotion(st.hi - st.lo, reuploaded=reup)
         self.tier_meter.staged_commits += 1
@@ -251,8 +253,9 @@ class TieredCorpus:
         if reset:
             for w in self.windows:
                 w.restore_cursor({"n_valid": 0})
-        for w, r in zip(self.windows, rows):
-            w.append(r)          # DeviceWindow meters this upload itself
+        with obs_span(self._recorder, "plane.append", staged=False):
+            for w, r in zip(self.windows, rows):
+                w.append(r)      # DeviceWindow meters this upload itself
         reup = max(0, min(hi, prev_hi) - max(lo, prev_lo))
         self.tier_meter.record_promotion(hi - lo, reuploaded=reup)
         self.tier_meter.direct_builds += 1

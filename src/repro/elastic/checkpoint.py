@@ -16,7 +16,6 @@ the exact carries the next stage starts from.
 """
 from __future__ import annotations
 
-import contextlib
 import dataclasses
 import json
 import os
@@ -30,6 +29,7 @@ from ..data.tiers import TieredCorpus
 from ..data.tiers.ckpt import (is_lane_pointer, load_lane_slices,
                                unlink_lane_slices, write_lane_slices)
 from ..dist.runtime import DistributedDataset
+from ..obs.events import obs_span
 
 
 # ------------------------------------------------------------ dataset state
@@ -181,10 +181,8 @@ class StageCheckpointer:
         self.save(end)
 
     def save(self, end: StageEnd) -> pathlib.Path:
-        span = self.recorder.span(
-            "checkpoint.publish", stage=end.info.stage, n_t=end.info.n_t) \
-            if self.recorder is not None else contextlib.nullcontext()
-        with span:
+        with obs_span(self.recorder, "checkpoint.publish",
+                      stage=end.info.stage, n_t=end.info.n_t):
             return self._save(end)
 
     def _save(self, end: StageEnd) -> pathlib.Path:

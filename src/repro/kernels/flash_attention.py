@@ -87,6 +87,7 @@ def flash_attention(q, k, v, *, causal: bool = True, window: int = 0,
     return pl.pallas_call(
         functools.partial(_kernel, scale=scale, causal=causal, window=window,
                           bq=bq, bk=bk, nk=nk),
+        name="flash_attention",
         grid=(B, H, nq, nk),
         in_specs=[
             pl.BlockSpec((1, 1, bq, hd), lambda b, h, i, j: (b, h, i, 0)),

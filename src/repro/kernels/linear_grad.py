@@ -56,6 +56,7 @@ def linear_value_grad(X, y, w, *, loss: str = "squared_hinge",
     grid = (n // block_m,)
     g, l = pl.pallas_call(
         functools.partial(_kernel, loss=loss),
+        name="linear_grad",
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_m, d), lambda i: (i, 0)),

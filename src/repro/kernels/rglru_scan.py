@@ -64,6 +64,7 @@ def rglru_scan(a, b, *, block_w: int = 256, block_t: int = 512,
     G = rows_per_group(a.dtype)
     return pl.pallas_call(
         functools.partial(_kernel, ts=ts, G=G),
+        name="rglru_scan",
         grid=(B, W // bw, S // ts),
         in_specs=[
             pl.BlockSpec((1, ts, bw), lambda i, j, t: (i, t, j)),

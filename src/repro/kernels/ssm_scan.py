@@ -93,6 +93,7 @@ def ssm_scan(u, delta, B_ssm, C_ssm, A_log, D, *, block_d: int = 256,
     Ct = jnp.swapaxes(C_ssm, 1, 2)
     return pl.pallas_call(
         functools.partial(_kernel, ts=ts, G=G),
+        name="ssm_scan",
         grid=(Bsz, di // bd, S // ts),
         in_specs=[
             pl.BlockSpec((1, ts, bd), lambda b, i, t: (b, t, i)),

@@ -11,9 +11,10 @@
             expansion decisions, claim recomputation
 ``regress`` bench regression sentinel: BENCH_*.json vs committed anchors,
             BENCH_history.jsonl trajectory rendering
-``profile`` opt-in jax.profiler capture + per-stage HLO FLOP/byte estimates
-            (import ``repro.obs.profile`` directly — it needs jax; the rest
-            of the package stays stdlib+numpy importable)
+``profile`` jax.profiler capture, the compiled stage kernel's op -> scope
+            map, HLO FLOP/byte estimates (import ``repro.obs.profile``
+            directly — it needs jax; the rest of the package stays
+            stdlib+numpy importable)
 """
 from .events import (Event, EventRecorder, chrome_trace, from_jsonl,
                      read_log, validate_events, write_jsonl)

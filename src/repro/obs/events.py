@@ -28,13 +28,17 @@ tracks; the ``host`` tag maps to the process lane.
 
 Recording is allocation-light but not free: the stack only emits when a
 recorder is wired (``ObsSpec.enabled``); every hook is a ``None`` check
-otherwise.
+otherwise (``obs_span``).  Each span is also a
+``jax.profiler.TraceAnnotation`` once JAX is loaded, so a captured profiler
+trace holds the program's spans on the device trace's clock; this module
+itself never imports JAX.
 """
 from __future__ import annotations
 
 import contextlib
 import dataclasses
 import json
+import sys
 import threading
 import time
 from typing import Any, Iterable
@@ -141,7 +145,8 @@ class EventRecorder:
         extra: dict = {}
         t0 = self._now()
         try:
-            yield extra
+            with _annotation(name):
+                yield extra
         finally:
             self._emit(name, "span", t0, self._now() - t0,
                        tags, {**fields, **extra})
@@ -190,6 +195,24 @@ class EventRecorder:
         with open(path, "w") as fh:
             json.dump(out, fh, default=_json_safe)
         return len(out["traceEvents"])
+
+
+def _annotation(name: str):
+    """A ``jax.profiler.TraceAnnotation`` where the process has loaded JAX
+    (a profiler capture then holds the span), a no-op otherwise."""
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return contextlib.nullcontext()
+    return jax.profiler.TraceAnnotation(name)
+
+
+def obs_span(recorder, name: str, *, tags: dict | None = None, **fields):
+    """``recorder.span(...)`` when a recorder is wired, a no-op otherwise:
+    the one ``None`` check every instrumented hot path pays with
+    observability off."""
+    if recorder is None:
+        return contextlib.nullcontext({})
+    return recorder.span(name, tags=tags, **fields)
 
 
 def _json_safe(v):

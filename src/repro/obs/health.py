@@ -8,11 +8,12 @@ every emission through a set of streaming detectors:
   ===========================  ==========================================
   detector                     fires when
   ===========================  ==========================================
-  ``straggler``                one host's recent shard-load pace exceeds
-                               ``ratio``× the median of the other hosts'
-                               (per-host ``meter.load`` durations — the
-                               signal a ``FaultPlan`` ``slow@`` injection
-                               or a genuinely sick host produces)
+  ``straggler``                one host's shard-load pace in a stage
+                               exceeds ``ratio``× the median of the
+                               other hosts' (per-host ``meter.load``
+                               durations — the signal a ``FaultPlan``
+                               ``slow@`` injection or a genuinely sick
+                               host produces)
   ``expansion_stall``          a ``TrafficDriven`` policy's consecutive
                                holds reach ``hold_frac`` of
                                ``max_hold_chunks`` (the stage is about to
@@ -43,6 +44,7 @@ import dataclasses
 import json
 import math
 import pathlib
+import statistics
 import threading
 
 #: ``ObsSpec.slo`` knobs and their defaults.  ``max_hold_chunks`` is
@@ -87,10 +89,14 @@ class Detector:
 class StragglerDetector(Detector):
     """Per-host shard-load pace outliers.
 
-    Tracks a trailing window of ``meter.load`` durations per host; a host
-    whose recent mean pace exceeds ``ratio`` × the median of the other
-    hosts' is flagged (once per host per stage — a slowed host re-flags
-    as the run progresses, a recovered one stops)."""
+    Tracks a trailing window of ``meter.load`` durations per host within
+    the current stage; a host whose pace, the median of its window,
+    exceeds ``ratio`` × the median of the other hosts' is flagged (once
+    per host per stage — a slowed host re-flags as the run progresses, a
+    recovered one stops).  Hosts are compared within a stage, by medians:
+    a stall of the whole process (a compile holding the interpreter, a
+    loaded machine) delays the loads of every host at once, and carried
+    across stages in a mean it outweighed a slow host's excess."""
     kind = "straggler"
 
     def __init__(self, *, ratio: float = 3.0, min_loads: int = 3,
@@ -98,23 +104,25 @@ class StragglerDetector(Detector):
         self.ratio = float(ratio)
         self.min_loads = int(min_loads)
         self.window = int(window)
-        self._durs: dict[int, list[float]] = {}
+        self._durs: dict[int, list[float]] = {}     # this stage's loads
+        self._hosts: set = set()
         self._flagged: set = set()
         self.stage: int | None = None
 
     def _pace(self, host) -> float:
-        durs = self._durs[host]
-        return sum(durs) / len(durs)
+        return statistics.median(self._durs[host])
 
     def observe(self, event: dict) -> Detection | None:
         if event["name"] == "stage.begin":
             self.stage = event["tags"].get("stage")
+            self._durs.clear()
             return None
         if event["name"] != "meter.load":
             return None
         host = event["tags"].get("host")
         if not isinstance(host, int):
             return None
+        self._hosts.add(host)
         durs = self._durs.setdefault(host, [])
         durs.append(float(event["fields"].get("duration_s", 0.0)))
         del durs[:-self.window]
@@ -122,10 +130,7 @@ class StragglerDetector(Detector):
                   if h != host and len(d) >= self.min_loads]
         if len(durs) < self.min_loads or not others:
             return None
-        others.sort()
-        m = len(others) // 2
-        median = others[m] if len(others) % 2 else \
-            0.5 * (others[m - 1] + others[m])
+        median = statistics.median(others)
         pace = self._pace(host)
         key = (host, self.stage)
         if median > 0 and pace > self.ratio * median and \
@@ -141,7 +146,7 @@ class StragglerDetector(Detector):
         return None
 
     def summary(self) -> dict:
-        return {"hosts": sorted(self._durs),
+        return {"hosts": sorted(self._hosts),
                 "flagged": sorted(str(k) for k in self._flagged)}
 
 

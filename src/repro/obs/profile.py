@@ -1,25 +1,29 @@
-"""Opt-in profiling: ``jax.profiler`` capture + HLO FLOP/byte estimates.
-
-Two complementary views, both riding the existing ``launch/hlo.py`` path:
+"""Profiling: ``jax.profiler`` capture, the compiled stage kernel's op map,
+and HLO FLOP/byte estimates.
 
   * ``profiler_trace(logdir)`` wraps a run in ``jax.profiler.trace`` so the
-    XLA-level timeline lands in TensorBoard format (``ObsSpec.profile`` +
-    ``jax_profiler_dir``);
-  * ``StageProfiler`` lowers each stage's kernel once per (kernel, window)
-    shape and emits a ``profile.stage`` event with analytic FLOPs, bytes and
-    roofline seconds — the per-stage cost model the ROADMAP's pallas-fusion
-    arc tunes against.  Lowering is cached and failures degrade to an
-    ``error`` field; profiling must never kill a run.
-
-``seed_kernel_costs`` applies the same estimator to the seed pallas-kernel
-oracles (benchmarks/roofline.py plots these).
+    XLA-level timeline lands in TensorBoard format
+    (``ObsSpec.jax_profiler_dir``);
+  * ``StageProfiler`` (wired whenever a recorder is) keeps each stage-kernel
+    executable the engine runs, and ``stage_op_names`` maps its compiled
+    HLO instructions, the names a device trace's ops carry, to their
+    ``op_name`` metadata: the ``jax.named_scope``s ``bet.batch``,
+    ``bet.forward`` (its backward is ``transpose(jvp(bet.forward))``),
+    ``bet.optimizer`` and ``bet.probe`` say which part of the step an op
+    belongs to;
+  * ``hlo_cost`` estimates FLOPs and bytes of one compiled function, and
+    ``seed_kernel_costs`` applies it to the seed pallas-kernel oracles
+    (benchmarks/roofline.py plots these).
 
 Deliberately NOT imported by ``repro.obs.__init__``: this module needs jax;
 events/metrics/report stay stdlib-importable.
 """
 from __future__ import annotations
 
+import collections
 import contextlib
+import re
+import time
 
 import jax
 import jax.numpy as jnp
@@ -123,29 +127,97 @@ def seed_kernel_costs() -> dict:
 
 
 # ------------------------------------------------------------ stage profiling
-class StageProfiler:
-    """Per-stage analytic cost events.  The engine calls ``observe`` before
-    each stage's first kernel launch; the profiler lowers the same callable
-    on the same arguments once per (kernel, window size) and emits one
-    ``profile.stage`` event.  Every failure mode is caught and reported in
-    the event — profiling never alters the run."""
+#: Stage-kernel executables observed while a recorder was wired, oldest
+#: first: signature -> [Lowered, perf_counter time last observed].  Like
+#: ``ops.CALLS``, one registry per process; the oldest entry goes once it
+#: holds ``MAX_KERNELS``.
+STAGE_KERNELS: collections.OrderedDict = collections.OrderedDict()
+MAX_KERNELS = 16
 
-    def __init__(self, recorder):
-        self.recorder = recorder
-        self._seen: set = set()
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) .*\{$")
+_INSTRUCTION = re.compile(r"^\s+(?:ROOT )?%?([\w.\-]+) = ")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+_CALLS = re.compile(r"calls=%?([\w.\-]+)")
+_OPERAND = re.compile(r"(?<![=\w])%([\w.\-]+)")
+
+
+def _signature(kernel, args, kwargs) -> tuple:
+    leaves, tree = jax.tree_util.tree_flatten(args)
+    return (id(kernel), tree, tuple(sorted(kwargs.items())),
+            tuple((jnp.shape(x), str(jnp.result_type(x)),
+                   getattr(x, "sharding", None)) for x in leaves))
+
+
+def hlo_op_names(hlo_text: str) -> dict:
+    """``{HLO instruction name: (op_name, ...)}`` of one compiled module's
+    text.  An instruction has its own ``op_name`` metadata; one without
+    takes those of the instructions in its fused computation (a fusion),
+    else of its operands, else of its users: the compiler's async copies
+    and slices into fast memory are named by the op they load from or
+    feed."""
+    own, calls, operands, body, comp = {}, {}, {}, {}, None
+    for line in hlo_text.splitlines():
+        m = _COMPUTATION.match(line)
+        if m:
+            comp = body.setdefault(m.group(1), [])
+            continue
+        m = _INSTRUCTION.match(line)
+        if m is None or comp is None:
+            continue
+        name = m.group(1)
+        comp.append(name)
+        op = _OP_NAME.search(line)
+        own[name] = (op.group(1),) if op else ()
+        c = _CALLS.search(line)
+        if c:
+            calls[name] = c.group(1)
+        operands[name] = [o for o in _OPERAND.findall(line, m.end())
+                          if o in own]
+
+    def fused(inst: str) -> tuple:
+        out = list(own[inst])
+        for i in () if out else body.get(calls.get(inst), ()):
+            out.extend(n for n in fused(i) if n not in out)
+        return tuple(out)
+
+    names = {i: fused(i) for i in own}
+    users: dict = {}
+    for i, ops in operands.items():
+        for o in ops:
+            users.setdefault(o, []).append(i)
+    # the text lists operands before their users, so one pass each way
+    for order, links in ((list(own), operands),
+                         (list(reversed(own)), users)):
+        for i in order:
+            if not names[i]:
+                names[i] = tuple(dict.fromkeys(
+                    n for j in links.get(i, ()) for n in names[j]))
+    return names
+
+
+def stage_op_names(since: float | None = None) -> list:
+    """One ``hlo_op_names`` map per stage-kernel executable observed at or
+    after ``since`` (``perf_counter`` time; None: every one).  Built on
+    request, never on the hot path: each compile() finds the executable the
+    observed call already compiled, so nothing compiles again."""
+    return [hlo_op_names(lowered.compile().as_text())
+            for lowered, seen in list(STAGE_KERNELS.values())
+            if since is None or seen >= since]
+
+
+class StageProfiler:
+    """The engine's hook for reading its compiled stage kernels.  The engine
+    calls ``observe`` before a stage's first kernel launch (the kernel
+    donates its carries, so after the call they are gone); each new
+    (kernel, argument shapes and shardings) is lowered there, which the
+    call that follows reuses, and kept in ``STAGE_KERNELS``.  Nothing
+    compiles here."""
 
     def observe(self, info, kernel, args, kwargs) -> None:
-        n_t = int(getattr(info, "n_t", 0))
-        key = (id(kernel), n_t)
-        if key in self._seen:
-            return
-        self._seen.add(key)
-        fields = {"stage": int(getattr(info, "stage", -1)), "n_t": n_t}
-        try:
-            static = tuple(k for k, v in kwargs.items()
-                           if isinstance(v, int) and not isinstance(v, bool))
-            cost = hlo_cost(kernel, *args, static_argnames=static, **kwargs)
-            fields.update(cost)
-        except Exception as exc:
-            fields["error"] = f"{type(exc).__name__}: {exc}"
-        self.recorder.instant("profile.stage", **fields)
+        key = _signature(kernel, args, kwargs)
+        entry = STAGE_KERNELS.get(key)
+        if entry is None:
+            entry = STAGE_KERNELS[key] = [kernel.lower(*args, **kwargs), 0.0]
+            while len(STAGE_KERNELS) > MAX_KERNELS:
+                STAGE_KERNELS.popitem(last=False)
+        entry[1] = time.perf_counter()
